@@ -22,10 +22,13 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.data import make_classification, partition_label_skew  # noqa: E402
 from repro.fl import FLConfig, FLSimulation  # noqa: E402
 from repro.models.vision import accuracy, init_mlp, mlp_logits, xent_loss  # noqa: E402
 from repro.sim import Task  # noqa: E402
+
+enable_compile_cache()
 
 # Benchmark scale (CPU container): paper protocol at reduced scale.
 ROUNDS = int(os.environ.get("PROBIT_BENCH_ROUNDS", "60"))
@@ -92,6 +95,22 @@ def timed(fn, *args, reps: int = 20, warmup: int = 3) -> float:
         jax.block_until_ready(fn(*args))
         ts.append((time.perf_counter() - t0) * 1e6)
     return float(np.median(ts))
+
+
+def require_cpu_sweep(what: str) -> None:
+    """Refuse a CPU virtual-device sweep anywhere but the CPU backend.
+
+    Such a sweep spawns one child per device count, each forcing a CPU
+    device count through ``XLA_FLAGS``. On an accelerator the flag means
+    nothing, and the parent, having touched JAX, already holds the chip
+    that every child would need (one process per chip)."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what} sweeps CPU virtual devices in child processes; it runs "
+            f"on the CPU backend only, not on {backend} (this process holds "
+            f"the chip)"
+        )
 
 
 def emit(name: str, us_per_call: float, derived: str):

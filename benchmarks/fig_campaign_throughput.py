@@ -86,7 +86,9 @@ def run_inner(rounds: int | None = None, reps: int = REPS) -> dict:
 
 
 def main(rounds: int | None = None, device_counts=DEVICE_COUNTS) -> dict:
-    from .common import emit
+    from .common import emit, require_cpu_sweep
+
+    require_cpu_sweep("fig_campaign_throughput")
 
     out: dict = {"rounds": rounds or ROUNDS, "sweep": {}}
     for n_dev in device_counts:

@@ -235,8 +235,9 @@ def run_sharded(m: int, rounds: int, n_dev: int) -> dict:
 
 
 def main(rounds: int | None = None, m_grid=None) -> dict:
-    from .common import emit
+    from .common import emit, require_cpu_sweep
 
+    require_cpu_sweep("fig_streaming_clients (its sharded point)")
     rounds = ROUNDS if rounds is None else min(rounds, ROUNDS)
     m_grid = tuple(m_grid or M_GRID)
     out: dict = {"rounds": rounds, "chunk": CHUNK, "sweep": {}}

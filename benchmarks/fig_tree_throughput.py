@@ -251,8 +251,11 @@ def byz_campaign(rounds: int, seeds=(0, 1, 2)) -> dict:
 
 
 def main(rounds: int | None = None, smoke: bool = False) -> dict:
-    from .common import emit
+    import jax
 
+    from .common import emit, require_cpu_sweep
+
+    require_cpu_sweep("fig_tree_throughput")
     rounds = ROUNDS if rounds is None else min(rounds, ROUNDS)
     m = 10_000 if smoke else M_SWEEP
     edge_counts = (1, 2) if smoke else EDGE_COUNTS
@@ -297,11 +300,12 @@ def main(rounds: int | None = None, smoke: bool = False) -> dict:
     with open(os.path.join(REPORTS, "fig_tree_throughput.json"), "w") as f:
         json.dump(out, f, indent=1, default=str)
     if not smoke:
-        # the stable tracked number: clients/sec at edges {1, max}, M, CPU
+        # the stable tracked number: clients/sec at edges {1, max}, M,
+        # stamped with the backend that produced it
         bench = {
             "m": m,
             "rounds": rounds,
-            "platform": "cpu",
+            "platform": jax.default_backend(),
             "clients_per_sec": {
                 "flat": round(out["flat_baseline"]["clients_per_sec"]),
                 **{

@@ -25,6 +25,7 @@ def main() -> None:
     from . import fig_campaign_throughput, fig_streaming_clients
     from . import fig_bits_frontier, fig_tree_throughput
     from . import theorem_rates, kernels_micro, roofline
+    import jax
 
     results = {}
     print("name,us_per_call,derived")
@@ -42,17 +43,25 @@ def main() -> None:
     results["fig_async"] = fig_async_staleness.main(rounds)
     print("# --- Privacy amplification: participation x eps x aggregator ---")
     results["fig_privacy"] = fig_privacy_amplification.main(rounds)
-    print("# --- Campaign throughput: cells/sec vs virtual device count ---")
-    results["fig_throughput"] = fig_campaign_throughput.main(rounds)
-    print("# --- Streaming clients: dense vs chunked vs sharded M-sweep ---")
-    results["fig_streaming"] = fig_streaming_clients.main(
-        m_grid=(1_000, 10_000, 100_000) if args.quick else None
-    )
+    # The CPU virtual-device sweeps spawn children that would each need
+    # the chip this process holds; on an accelerator they are not started.
+    cpu = jax.default_backend() == "cpu"
+    if cpu:
+        print("# --- Campaign throughput: cells/sec vs virtual device count ---")
+        results["fig_throughput"] = fig_campaign_throughput.main(rounds)
+        print("# --- Streaming clients: dense vs chunked vs sharded M-sweep ---")
+        results["fig_streaming"] = fig_streaming_clients.main(
+            m_grid=(1_000, 10_000, 100_000) if args.quick else None
+        )
+    else:
+        print("# skipped off the CPU backend: fig_campaign_throughput, "
+              "fig_streaming_clients, fig_tree_throughput")
     print("# --- Bits frontier: wire_bits x byz_frac x eps grid ---")
     results["fig_bits"] = fig_bits_frontier.main(rounds)
-    print("# --- Tree throughput: clients/sec vs edge count ---")
-    # --quick runs the reduced (smoke) grid: smaller M, fewer edge counts
-    results["fig_tree"] = fig_tree_throughput.main(rounds, smoke=args.quick)
+    if cpu:
+        print("# --- Tree throughput: clients/sec vs edge count ---")
+        # --quick runs the reduced (smoke) grid: smaller M, fewer edge counts
+        results["fig_tree"] = fig_tree_throughput.main(rounds, smoke=args.quick)
 
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "w") as f:

@@ -34,7 +34,6 @@ from repro.core import build_pipeline
 from repro.data import make_lm_streams
 from repro.fl.pytree_wire import pytree_wire_bytes
 from repro.launch.fl_step import DistFLConfig, make_fl_train_step
-from repro.distributed import set_mesh
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_specs
 from repro.models.config import ModelConfig
@@ -127,7 +126,7 @@ def main():
     rounds = args.rounds or (300 if args.full else 30)
 
     cfg = model_config(args.full)
-    with set_mesh(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         specs = build_specs(cfg)
         print(f"{cfg.name}: {count_params(specs)/1e6:.1f}M params, {rounds} rounds")
         params0 = init_params(specs, jax.random.PRNGKey(0))

@@ -13,10 +13,9 @@ two subsystems rely on:
 * **device sharding** of campaign batches: random ops lower to
   per-element counter hashes with no cross-device layout dependence.
 
-This is also the default stream in jax >= 0.5, so pinning it keeps seeds
-stable across the jax versions the compat shims in ``repro.distributed``
-support. (Trajectories differ from the legacy stream; every
-seed-calibrated test threshold was re-verified green on the new stream
+This is also the installed JAX's default stream; pinning it keeps seeds
+stable should that default change. (Trajectories differ from the legacy
+stream; every seed-calibrated test threshold was re-verified green on the new stream
 when this landed — the PR-3 20-seed calibrations held without retuning.)
 """
 

@@ -77,4 +77,19 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     )
 
 
-__all__ = ["ARCH_IDS", "get_config", "reduced", "SHAPES", "ShapeConfig"]
+def with_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """Depth-only cut: the first ``n_layers`` whole layers at the published
+    widths (d_model, heads, d_ff, vocab unchanged)."""
+    if n_layers < 1 or n_layers > cfg.n_layers or n_layers % cfg.unit:
+        raise ValueError(
+            f"{cfg.name}: n_layers must be a multiple of the {cfg.unit}-layer "
+            f"pattern in [1, {cfg.n_layers}], got {n_layers}"
+        )
+    if n_layers == cfg.n_layers:
+        return cfg
+    return dataclasses.replace(cfg, name=f"{cfg.name}-{n_layers}L", n_layers=n_layers)
+
+
+__all__ = [
+    "ARCH_IDS", "get_config", "reduced", "with_depth", "SHAPES", "ShapeConfig",
+]

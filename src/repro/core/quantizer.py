@@ -47,7 +47,6 @@ __all__ = [
     "pack_bits",
     "unpack_bits",
     "codes_to_counts",
-    "byte_popcount",
     "PACK_CHUNK",
     "WIRE_BITS",
     "wire_bytes",
@@ -163,15 +162,6 @@ def unpack_bits(packed: jax.Array, n: int) -> jax.Array:
 def codes_to_counts(codes: jax.Array) -> jax.Array:
     """``N_i`` of Eq. 12: number of +1 codes across the leading client axis."""
     return jnp.sum((codes > 0).astype(jnp.int32), axis=0)
-
-
-def byte_popcount(x: jax.Array) -> jax.Array:
-    """Per-byte bit count: ``jax.lax.population_count`` with a uint8-LUT
-    fallback for backends/versions without the primitive."""
-    if hasattr(jax.lax, "population_count"):
-        return jax.lax.population_count(x)
-    lut = jnp.asarray([bin(i).count("1") for i in range(256)], jnp.uint8)
-    return lut[x.astype(jnp.uint8)]
 
 
 # ---------------------------------------------------------------------------
@@ -510,7 +500,7 @@ def _popcount_colsums(pch: jax.Array) -> jax.Array:
     (M, cb) uint8 -> (cb * 8,) int32, column order byte-major / LSB-first
     (bit k of byte j is coordinate ``8 j + k``). Clients are grouped into
     octets of 8; the bit-k's of an octet's bytes are re-packed into one
-    byte, whose :func:`byte_popcount` counts 8 clients' votes at once —
+    byte, whose ``jax.lax.population_count`` counts 8 clients' votes at once —
     the client reduction shortens 8x (M -> M/8 octets) and the widest
     intermediate stays uint8 instead of int32. Zero pad rows (M % 8)
     contribute zero bits, so the counts are exactly the unpack-and-sum
@@ -524,7 +514,7 @@ def _popcount_colsums(pch: jax.Array) -> jax.Array:
     octet = jnp.sum(
         bit_k << shifts[None, :, None, None], axis=1, dtype=jnp.uint8
     )  # (G, cb, 8) client-major bytes: bit g of octet[., j, k] = client bit
-    counts = jnp.sum(byte_popcount(octet).astype(jnp.int32), axis=0)
+    counts = jnp.sum(jax.lax.population_count(octet).astype(jnp.int32), axis=0)
     return counts.reshape(cb * 8)
 
 

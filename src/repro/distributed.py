@@ -82,21 +82,10 @@ _RULES: dict[str, tuple[str, ...]] = {
 }
 
 
-def set_mesh(mesh):
-    """Compat context: ``jax.set_mesh`` on new JAX; on jax<=0.4 the Mesh
-    object is itself the (thread-resources) context manager."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
 def current_mesh():
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:  # new global-mesh API
-        am = get_am()
-        return None if am.empty else am
-    from jax._src.mesh import thread_resources  # jax<=0.4 fallback
-
-    pm = thread_resources.env.physical_mesh
-    return None if pm.empty else pm
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def _axis_entry(mesh, name: str | None, dim: int, used: set[str] | None = None):
@@ -154,7 +143,6 @@ def shard(x: jax.Array, *logical: str | None) -> jax.Array:
 
 def named_sharding(mesh: Mesh, logical: tuple[str | None, ...], shape) -> NamedSharding:
     """Concrete NamedSharding for placing inputs / params on a real mesh."""
-    # spec_for needs the mesh context; compute via a temporary set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         spec = spec_for(logical, tuple(shape))
     return NamedSharding(mesh, spec)

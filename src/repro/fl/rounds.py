@@ -754,12 +754,7 @@ def _sharded_scan(
 
     in_specs = (P("data"), P("data")) + (P(),) * 7
     kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=P())
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map(body, check_vma=False, **kwargs)
-    else:
-        from jax.experimental.shard_map import shard_map
-
-        fn = shard_map(body, check_rep=False, **kwargs)
+    fn = jax.shard_map(body, check_vma=False, **kwargs)
     return fn(
         ctx.client_x, ctx.client_y, kb, k_att, k_q,
         w_global, b_scalar, jnp.asarray(limit, jnp.int32), params,

@@ -44,7 +44,7 @@ from ..core.quantizer import (
     packed_quantize_batch,
 )
 from .stoch_quant import LANES, stoch_quant_ef_2d, stoch_quant_pack_2d
-from .bit_aggregate import bit_aggregate_2d
+from .bit_aggregate import bit_count_2d
 from .prox_sgd import prox_sgd_2d
 from . import ref
 
@@ -309,20 +309,21 @@ def bit_aggregate(
 ) -> jax.Array:
     """packed (M, P) uint8 (P = padded_len(n)/8), b (n,) -> theta_hat (n,).
 
-    The vote count is popcount-based on every engine
-    (``jax.lax.population_count`` after an octet bit-transpose) and
-    bit-exact with ``repro.core.quantizer.packed_counts``; pad columns are
-    sliced away before the estimate so tail lanes can never leak.
+    Every engine counts the same integers as
+    ``repro.core.quantizer.packed_counts`` (the ref engine is that
+    popcount reduction; the kernel sums bit planes), slices the pad
+    columns away so tail lanes can never leak, and applies the Eq.-13
+    expression of ``ml_estimate_from_counts`` to the same (n,) operands.
     """
     engine = _engine_arg(engine, interpret)
     m = packed.shape[0]
     b_full = jnp.broadcast_to(b, (n,)).astype(jnp.float32)
     if engine == "ref":
-        counts = packed_counts(packed)[:n]
-        return (2.0 * counts.astype(jnp.float32) - m) / m * b_full
-    b2 = _pad_to_rows(b_full, 0.0)
-    theta2 = bit_aggregate_2d(packed, b2, interpret=engine == "interpret")
-    return theta2.reshape(-1)[:n]
+        counts = packed_counts(packed)
+    else:
+        counts = bit_count_2d(packed, interpret=engine == "interpret")
+    counts = counts.reshape(-1)[:n]
+    return (2.0 * counts.astype(jnp.float32) - m) / m * b_full
 
 
 @functools.partial(jax.jit, static_argnames=("engine", "interpret"))

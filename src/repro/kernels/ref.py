@@ -12,8 +12,8 @@ Two jobs, one implementation:
 
 To guarantee the oracle can never drift from the production pure-JAX wire,
 these functions are thin compositions of the :mod:`repro.core.quantizer`
-primitives (``binarize_prob``, ``_pack_bool_lastdim``, ``byte_popcount``)
-rather than re-implementations. Imports are deferred to call time to keep
+primitives (``binarize_prob``, ``_pack_bool_lastdim``) and
+``jax.lax.population_count`` rather than re-implementations. Imports are deferred to call time to keep
 ``repro.kernels`` importable without ``repro.core`` (and vice versa).
 """
 

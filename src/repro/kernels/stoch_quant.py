@@ -9,10 +9,12 @@ error-feedback carry in and emits the next residual ``eff - c * b`` from
 the same VMEM-resident block, so a sparsified/EF client touches HBM once
 per parameter instead of three times (quantize, re-unpack, subtract).
 
-Layout: the flat parameter vector is viewed as ``(rows, 1024)`` — the last
-dim is 8 x 128 (sublane x lane) aligned; packing reduces 1024 lanes of f32
-to 128 lanes of uint8, both hardware-tile-aligned. The in-kernel
-``reshape(br, 128, 8)`` is a VREG relayout the Mosaic compiler handles.
+Layout: the flat parameter vector is viewed as ``(rows, 1024)``; packing
+reduces 1024 lanes of f32 to 128 lanes of uint8, both tile-aligned. Byte
+``j`` of a row holds lanes ``8j..8j+7``, LSB first — the wire order. Mosaic
+cannot reshape lanes into a trailing 8, so the 8-lane group sum runs as a
+matmul with a constant 0/power-of-two matrix (:func:`pack_weights`) on
+the MXU, exact at any precision.
 
 Dispatch policy (see :mod:`repro.kernels.ops`): compiled Pallas on TPU,
 the pure-JAX wire in :mod:`repro.kernels.ref` elsewhere; ``interpret=True``
@@ -39,25 +41,41 @@ def _binarize(d, b, u):
     return u < p
 
 
-def _pack(bits):
-    br = bits.shape[0]
-    b8 = bits.astype(jnp.uint8).reshape(br, LANES // 8, 8)
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    return jnp.sum(b8 << shifts, axis=-1).astype(jnp.uint8)
+def pack_weights() -> jax.Array:
+    """(1024, 128) f32 matrix ``W[l, j] = 2**(l % 8)`` iff ``l // 8 == j``.
+
+    ``bits @ W`` is the LSB-first byte of every 8 consecutive lanes: the
+    8-lane group reduction runs on the MXU, so the kernel never reshapes
+    lanes into a trailing 8 (Mosaic cannot). Every operand is 0, 1 or a
+    power of two up to 128 and every sum is at most 255, so the product
+    is exact at any matmul precision."""
+    lane = jnp.arange(LANES, dtype=jnp.int32)[:, None]
+    byte = jnp.arange(LANES // 8, dtype=jnp.int32)[None, :]
+    return jnp.where(lane // 8 == byte, 1 << (lane % 8), 0).astype(jnp.float32)
 
 
-def _kernel(delta_ref, b_ref, u_ref, out_ref):
+def _pack(bits, w):
+    byte = jnp.dot(
+        bits.astype(jnp.float32), w, preferred_element_type=jnp.float32
+    )
+    return byte.astype(jnp.int32).astype(jnp.uint8)
+
+
+def _kernel(delta_ref, b_ref, u_ref, w_ref, out_ref):
     d = delta_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
-    out_ref[...] = _pack(_binarize(d, b, u_ref[...]))
+    out_ref[...] = _pack(_binarize(d, b, u_ref[...]), w_ref[...])
 
 
-def _ef_kernel(delta_ref, res_ref, b_ref, u_ref, out_ref, new_res_ref):
+def _ef_kernel(delta_ref, res_ref, b_ref, u_ref, w_ref, out_ref, new_res_ref):
     eff = delta_ref[...].astype(jnp.float32) + res_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
     bits = _binarize(eff, b, u_ref[...])
-    out_ref[...] = _pack(bits)
+    out_ref[...] = _pack(bits, w_ref[...])
     new_res_ref[...] = eff - jnp.where(bits, b, -b)
+
+
+_W_SPEC = pl.BlockSpec((LANES, LANES // 8), lambda r: (0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -81,11 +99,12 @@ def stoch_quant_pack_2d(
             pl.BlockSpec((block_rows, LANES), lambda r: (r, 0)),
             pl.BlockSpec((block_rows, LANES), lambda r: (r, 0)),
             pl.BlockSpec((block_rows, LANES), lambda r: (r, 0)),
+            _W_SPEC,
         ],
         out_specs=pl.BlockSpec((block_rows, LANES // 8), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES // 8), jnp.uint8),
         interpret=interpret,
-    )(delta, b, uniforms)
+    )(delta, b, uniforms, pack_weights())
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -114,7 +133,7 @@ def stoch_quant_ef_2d(
     return pl.pallas_call(
         _ef_kernel,
         grid=grid,
-        in_specs=[spec_in] * 4,
+        in_specs=[spec_in] * 4 + [_W_SPEC],
         out_specs=[
             pl.BlockSpec((block_rows, LANES // 8), lambda r: (r, 0)),
             pl.BlockSpec((block_rows, LANES), lambda r: (r, 0)),
@@ -124,4 +143,4 @@ def stoch_quant_ef_2d(
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(delta, residual, b, uniforms)
+    )(delta, residual, b, uniforms, pack_weights())

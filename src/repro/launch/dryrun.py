@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import configs
-from ..distributed import set_mesh, spec_for, use_batch_axes, use_rules
+from ..distributed import spec_for, use_batch_axes, use_rules
 from ..models import (
     SHAPES,
     abstract_params,
@@ -200,7 +200,7 @@ def run_case(
             rules_ctx = contextlib.nullcontext()
             batch_ax = ("pod", "data") if (multi_pod and shape.kind != "train") else ("data",)
             fsdp = True
-        with set_mesh(mesh), indexed_params(indexed), rules_ctx, \
+        with jax.set_mesh(mesh), indexed_params(indexed), rules_ctx, \
                 inner_remat(layer_remat), remat_policy(remat), ssm_state_dtype(ssm_dtype):
             with use_batch_axes(*batch_ax):
                 fn, args = build_lowerable(cfg, shape, mesh, fl_clients, fl_agg, rand_bits, fsdp)

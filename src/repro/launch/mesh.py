@@ -11,14 +11,12 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the API supports them
-    (jax>=0.5); plain make_mesh on jax 0.4."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axis types (sharding by propagation)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,6 +30,16 @@ def make_host_mesh(model: int = 1):
     """Degenerate 1-device mesh for CPU integration tests of the
     distributed code path (same axis names as production)."""
     return make_mesh((1, model), ("data", "model"))
+
+
+def make_silo_mesh(devices=None):
+    """Cross-silo mesh: one silo per device on the "pod" axis.
+
+    ``("pod", "data", "model") = (n_devices, 1, 1)`` over ``devices``
+    (default: every local device), so ``fl_step`` trains one client per
+    device at each scan step and sums int32 vote counts across pods."""
+    devices = jax.devices() if devices is None else list(devices)
+    return make_mesh((len(devices), 1, 1), ("pod", "data", "model"), devices)
 
 
 def make_campaign_mesh(n_devices: int | None = None):

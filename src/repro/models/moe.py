@@ -143,18 +143,10 @@ def moe_block(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
             return jax.lax.psum(out, psum_axes)
 
         in_specs = (tok_spec, tok_spec, tok_spec, ew1, ew1, ew2)
-        if hasattr(jax, "shard_map"):
-            smapped = jax.shard_map(
-                local_fn, mesh=mesh, in_specs=in_specs, out_specs=tok_spec,
-                check_vma=False,
-            )
-        else:  # jax<=0.4: experimental API, check_rep instead of check_vma
-            from jax.experimental.shard_map import shard_map as _shard_map
-
-            smapped = _shard_map(
-                local_fn, mesh=mesh, in_specs=in_specs, out_specs=tok_spec,
-                check_rep=False,
-            )
+        smapped = jax.shard_map(
+            local_fn, mesh=mesh, in_specs=in_specs, out_specs=tok_spec,
+            check_vma=False,
+        )
         out2d = smapped(x2d, gates, idx, p["w1"], p["w3"], p["w2"])
     else:
         out2d = _expert_compute(

@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import configs
-from repro.distributed import set_mesh, spec_for, use_batch_axes
+from repro.distributed import spec_for, use_batch_axes
 from repro.launch.fl_step import DistFLConfig, make_fl_train_step
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_specs, sample_batch
@@ -31,7 +31,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def test_spec_rules_divisibility():
     mesh = make_host_mesh()  # sizes 1 -> everything divisible
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         assert spec_for(("batch", None), (4, 8)) == P("data", None)
         assert spec_for(("heads", None), (3, 8)) == P("model", None)
 
@@ -60,7 +60,7 @@ def test_fl_round_semantics_host_mesh():
     """The distributed FL round must decrease client loss and keep the
     global params finite on a 1-device mesh (pure semantics check)."""
     cfg = configs.reduced(configs.get_config("qwen2-1.5b"))
-    with set_mesh(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         specs = build_specs(cfg)
         params = init_params(specs, jax.random.PRNGKey(0))
         fl = DistFLConfig(clients_per_round=2, local_steps=2, lr=0.05)
@@ -85,7 +85,7 @@ def test_fl_round_semantics_host_mesh():
 def test_counts_bounded_by_clients():
     """Vote counts are in [0, M] — the ML estimate stays within [-b, b]."""
     cfg = configs.reduced(configs.get_config("qwen2-1.5b"))
-    with set_mesh(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         specs = build_specs(cfg)
         params = init_params(specs, jax.random.PRNGKey(0))
         p0 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
@@ -121,12 +121,12 @@ def test_dryrun_subprocess_8_devices(tmp_path):
         from repro.models.spec import param_pspecs
         from repro.launch.fl_step import DistFLConfig, make_fl_train_step
         from repro.models import input_specs, input_logical
-        from repro.distributed import set_mesh, spec_for
+        from repro.distributed import spec_for
         from repro.launch.mesh import make_mesh
 
         cfg = configs.reduced(configs.get_config("qwen3-moe-30b-a3b"))
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             specs = build_specs(cfg)
             pspecs = param_pspecs(specs, fsdp_axis="data")
             params_abs = jax.tree.map(
@@ -157,3 +157,50 @@ def test_dryrun_subprocess_8_devices(tmp_path):
     assert res.returncode == 0, res.stderr[-3000:]
     payload = json.loads(res.stdout.strip().splitlines()[-1])
     assert payload["ok"] and payload["has_collectives"]
+
+
+def test_with_depth_keeps_published_widths():
+    """--layers cuts whole layers only; every width stays published."""
+    full = configs.get_config("qwen2-1.5b")
+    cut = configs.with_depth(full, 2)
+    assert cut.n_layers == 2 and cut.name == "qwen2-1.5b-2L"
+    for field in ("d_model", "d_ff", "n_heads", "n_kv_heads", "vocab"):
+        assert getattr(cut, field) == getattr(full, field)
+    assert configs.with_depth(full, full.n_layers) is full
+    with pytest.raises(ValueError):
+        configs.with_depth(full, full.n_layers + 1)
+
+
+def test_pod_mesh_round_matches_one_device_subprocess():
+    """train.py --pod-mesh (one silo per device, 4 CPU devices) gives the
+    one-device round's params exactly: same cohort, key and int32 counts."""
+    script = textwrap.dedent(
+        """
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import json
+        import jax, numpy as np
+        from repro.launch import train
+
+        args = ["--arch", "qwen2-1.5b", "--reduced", "--rounds", "1",
+                "--clients", "4", "--local-steps", "2", "--per-batch", "2",
+                "--seq", "32"]
+        pod = train.main(args + ["--pod-mesh"])
+        one = train.main(args)
+        n_diff = sum(
+            int(np.sum(np.asarray(a, np.float32) != np.asarray(b, np.float32)))
+            for a, b in zip(jax.tree.leaves(pod["params"]),
+                            jax.tree.leaves(one["params"]))
+        )
+        print(json.dumps({"pods": [pod["pods"], one["pods"]], "n_diff": n_diff}))
+        """
+    )
+    env = dict(os.environ, PYTHONPATH=SRC)
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True, env=env,
+        timeout=540,
+    )
+    assert res.returncode == 0, res.stderr[-3000:]
+    payload = json.loads(res.stdout.strip().splitlines()[-1])
+    assert payload == {"pods": [4, 1], "n_diff": 0}

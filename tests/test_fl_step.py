@@ -25,7 +25,6 @@ from repro.core.bcontrol import (
     update_b_from_vote,
 )
 from repro.core.quantizer import threshold_u16, unpack_bits
-from repro.distributed import set_mesh
 from repro.launch import fl_step
 from repro.launch.fl_step import DistFLConfig, make_fl_train_step, update_b_dist
 from repro.launch.mesh import make_host_mesh
@@ -102,7 +101,7 @@ def test_fl_step_counts_exact_at_m300(monkeypatch, rand_bits):
         jnp.sum(l.astype(jnp.float32)) for l in jax.tree.leaves(p)
     )
     monkeypatch.setattr(fl_step, "train_loss", fake_loss)
-    with set_mesh(make_host_mesh()):
+    with jax.set_mesh(make_host_mesh()):
         specs = build_specs(cfg)
         params = init_params(specs, jax.random.PRNGKey(0))
         fl = DistFLConfig(

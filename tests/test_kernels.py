@@ -210,6 +210,30 @@ def test_bit_aggregate_equals_core_ml_estimate():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("m", [10, 37])
+def test_bit_aggregate_broadcast_b_matches_ref(m):
+    """A round builds b as one broadcast value inside its jit, and XLA
+    then reassociates Eq. 13 (b folds in before the division by M). The
+    kernel engine must hand XLA the same expression as the ref engine, or
+    the two round apart in the last bit."""
+    n = 5000
+    packed = jax.random.randint(
+        jax.random.PRNGKey(m), (m, ops.padded_len(n) // 8), 0, 256,
+        dtype=jnp.int32,
+    ).astype(jnp.uint8)
+
+    def theta(engine):
+        return jax.jit(
+            lambda p, s: ops.bit_aggregate(
+                p, jnp.full((n,), s), n, engine=engine
+            )
+        )(packed, jnp.float32(0.0104))
+
+    np.testing.assert_array_equal(
+        np.asarray(theta("interpret")), np.asarray(theta("ref"))
+    )
+
+
 @pytest.mark.parametrize("engine", ["ref", "interpret"])
 def test_bit_aggregate_padded_tail_never_leaks(engine):
     """n % 1024 != 0 and M % 8 != 0: adversarial all-ones pad lanes (both

@@ -32,7 +32,7 @@ import numpy as np
 import pytest
 
 from repro.core import build_pipeline
-from repro.core.quantizer import byte_popcount, packed_counts
+from repro.core.quantizer import packed_counts
 from repro.data import make_classification, partition_label_skew
 from repro.fl import rounds as R
 from repro.fl.runtime import FLConfig
@@ -131,7 +131,7 @@ def test_popcount_matches_unpack_reduction():
     np.testing.assert_array_equal(np.asarray(pop), bits.sum(0).astype(np.int32))
     bytes_ = jnp.arange(256, dtype=jnp.uint8)
     np.testing.assert_array_equal(
-        np.asarray(byte_popcount(bytes_)),
+        np.asarray(jax.lax.population_count(bytes_)),
         np.array([bin(v).count("1") for v in range(256)], np.uint8),
     )
 
