@@ -513,6 +513,7 @@ class ClientCompressor:
             )
         return None
 
+    @jax.named_scope("fl.compress")
     def compress(
         self,
         key: jax.Array,
@@ -724,6 +725,7 @@ class ServerAggregator:
         """
         return jnp.zeros((8 * p_bytes,), jnp.float32 if weighted else jnp.int32)
 
+    @jax.named_scope("fl.count")
     def accumulate_counts(
         self,
         counts: jax.Array,
@@ -737,6 +739,7 @@ class ServerAggregator:
             wire_chunk, weights_chunk, chunk=self.chunk
         )
 
+    @jax.named_scope("fl.finalize")
     def finalize(self, counts: jax.Array, m, b: jax.Array) -> jax.Array:
         """Per-scheme estimate from accumulated counts (slices pad bits)."""
         return self.from_counts(counts[: b.shape[0]], m, b)
@@ -747,6 +750,7 @@ class ServerAggregator:
         """Zero ``(sum_m w_m u_m, sum_m w_m)`` carry for dense streaming."""
         return jnp.zeros((d,), jnp.float32), jnp.float32(0.0)
 
+    @jax.named_scope("fl.count")
     def accumulate_sum(self, carry, updates: jax.Array, weights_chunk: jax.Array):
         s, w = carry
         return (
@@ -754,6 +758,7 @@ class ServerAggregator:
             w + jnp.sum(weights_chunk),
         )
 
+    @jax.named_scope("fl.finalize")
     def finalize_sum(self, carry) -> jax.Array:
         s, w = carry
         return jnp.where(w > 0, s / jnp.maximum(w, 1e-12), 0.0)
@@ -776,10 +781,11 @@ class ServerAggregator:
         wcounts = self.accumulate_counts(
             self.init_counts(p_bytes, weighted=True), wire.packed, weights
         )
-        wsum = jnp.sum(weights.astype(jnp.float32))
-        est = self.finalize(wcounts, jnp.maximum(wsum, 1e-12), wire.b)
-        # An all-empty buffer (round 0 under heavy latency) estimates zero.
-        return jnp.where(wsum > 0, est, 0.0)
+        with jax.named_scope("fl.finalize"):
+            wsum = jnp.sum(weights.astype(jnp.float32))
+            est = self.finalize(wcounts, jnp.maximum(wsum, 1e-12), wire.b)
+            # An all-empty buffer (round 0 under heavy latency) estimates zero.
+            return jnp.where(wsum > 0, est, 0.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -801,6 +807,7 @@ class ProBitPlusServer(ServerAggregator):
     def from_counts(self, counts, m, b):
         return ml_estimate_from_counts(counts, m, b)
 
+    @jax.named_scope("fl.finalize")
     def finalize(self, counts: jax.Array, m, b: jax.Array) -> jax.Array:
         if self.wire_bits == 1:
             return super().finalize(counts, m, b)
@@ -864,12 +871,13 @@ class ProBitPlusServer(ServerAggregator):
             # slices off, so realigning is lossless.
             pbytes = kops.padded_len(wire.d) // 8
             packed = wire.packed
-            if packed.shape[1] > pbytes:
-                packed = packed[:, :pbytes]
-            elif packed.shape[1] < pbytes:
-                packed = jnp.pad(
-                    packed, ((0, 0), (0, pbytes - packed.shape[1]))
-                )
+            with jax.named_scope("fl.count"):
+                if packed.shape[1] > pbytes:
+                    packed = packed[:, :pbytes]
+                elif packed.shape[1] < pbytes:
+                    packed = jnp.pad(
+                        packed, ((0, 0), (0, pbytes - packed.shape[1]))
+                    )
             return kops.bit_aggregate(packed, wire.b, wire.d)
         return super().aggregate(wire)
 
@@ -925,6 +933,7 @@ class AggregatorPipeline:
     compressor: ClientCompressor
     server: ServerAggregator
 
+    @jax.named_scope("fl.compress")
     def compress_wire(
         self,
         key: jax.Array,

@@ -63,6 +63,14 @@ operation-for-operation (same RNG schedule: client batches from one key,
 attack/quantizer keys from ``fold_in(key, 1)``, participation sampling
 from ``fold_in(key, 99)``), so a campaign cell at a fixed seed matches the
 sequential simulation to float tolerance.
+
+Each phase of a round runs under a ``jax.named_scope``: ``fl.gather``
+(cohort choice and the gathers of its state and batches), ``fl.train``,
+``fl.attack``, ``fl.compress`` and ``fl.count`` / ``fl.finalize`` (in
+:mod:`repro.core.aggregation`), ``fl.update`` (b-control, the global step,
+metrics) and ``fl.writeback`` (the per-client state). The scopes name the
+ops in a profiler trace (``bench/phases.py``); the compiled program is the
+same without them.
 """
 
 from __future__ import annotations
@@ -444,16 +452,17 @@ def _client_uploads(ctx, params, key, state, batches):
     unit-weight count path, see ``packed_weighted_counts``)."""
     cfg = ctx.cfg
     w_global = state.w_global
-    if cfg.participation < 1.0:
-        sel = jax.random.choice(
-            jax.random.fold_in(key, 99), cfg.n_clients,
-            (cfg.n_active,), replace=False,
-        )
-    else:
-        sel = jnp.arange(cfg.n_clients)
-    w_sel = state.w_locals[sel]
-    res_sel = state.residuals[sel]
-    batches = jax.tree.map(lambda a: a[sel], batches)
+    with jax.named_scope("fl.gather"):
+        if cfg.participation < 1.0:
+            sel = jax.random.choice(
+                jax.random.fold_in(key, 99), cfg.n_clients,
+                (cfg.n_active,), replace=False,
+            )
+        else:
+            sel = jnp.arange(cfg.n_clients)
+        w_sel = state.w_locals[sel]
+        res_sel = state.residuals[sel]
+        batches = jax.tree.map(lambda a: a[sel], batches)
 
     def client(w_local, cb, ck):
         return local_prox_train(
@@ -468,13 +477,15 @@ def _client_uploads(ctx, params, key, state, batches):
             use_kernel=cfg.use_kernels,
         )
 
-    ckeys = jax.random.split(key, cfg.n_active)
-    w_new, loss_before, loss_after = jax.vmap(client)(w_sel, batches, ckeys)
-    deltas = w_new - w_global[None]
+    with jax.named_scope("fl.train"):
+        ckeys = jax.random.split(key, cfg.n_active)
+        w_new, loss_before, loss_after = jax.vmap(client)(w_sel, batches, ckeys)
+        deltas = w_new - w_global[None]
 
-    k_att, k_q = jax.random.split(jax.random.fold_in(key, 1))
-    n_byz = int(cfg.n_active * cfg.byz_frac)
-    deltas_att = apply_attack(params.attack_id, k_att, deltas, n_byz)
+    with jax.named_scope("fl.attack"):
+        k_att, k_q = jax.random.split(jax.random.fold_in(key, 1))
+        n_byz = int(cfg.n_active * cfg.byz_frac)
+        deltas_att = apply_attack(params.attack_id, k_att, deltas, n_byz)
 
     wire, res_new = ctx.pipeline.compress_wire(
         k_q, deltas_att, state.b.b, res_sel,
@@ -492,27 +503,33 @@ def _finish_round(ctx, state, sel, w_new, loss_before, loss_after, res_new, thet
     theta_mse means. ``None`` keeps the exact unmasked ops.
     """
     cfg = ctx.cfg
-    bits = jax.vmap(loss_bit)(loss_before, loss_after)
-    b_new = update_b(state.b, bits, cfg.bctrl, weights=mask)
+    with jax.named_scope("fl.update"):
+        bits = jax.vmap(loss_bit)(loss_before, loss_after)
+        b_new = update_b(state.b, bits, cfg.bctrl, weights=mask)
+        w_global = state.w_global + theta
+    with jax.named_scope("fl.writeback"):
+        w_locals = state.w_locals.at[sel].set(w_new)
+        residuals = state.residuals.at[sel].set(res_new)
     new_state = state_cls(
-        w_global=state.w_global + theta,
-        w_locals=state.w_locals.at[sel].set(w_new),
+        w_global=w_global,
+        w_locals=w_locals,
         b=b_new,
-        residuals=state.residuals.at[sel].set(res_new),
+        residuals=residuals,
         **extra,
     )
-    if mask is None:
-        loss = jnp.mean(loss_after)
-        delta_mean = jnp.mean(deltas_att, axis=0)
-    else:
-        m_eff = jnp.maximum(jnp.sum(mask), 1.0)
-        loss = jnp.sum(loss_after * mask) / m_eff
-        delta_mean = jnp.sum(deltas_att * mask[:, None], axis=0) / m_eff
-    metrics = {
-        "loss": loss,
-        "b": b_new.b,
-        "theta_mse": jnp.mean((theta - delta_mean) ** 2),
-    }
+    with jax.named_scope("fl.update"):
+        if mask is None:
+            loss = jnp.mean(loss_after)
+            delta_mean = jnp.mean(deltas_att, axis=0)
+        else:
+            m_eff = jnp.maximum(jnp.sum(mask), 1.0)
+            loss = jnp.sum(loss_after * mask) / m_eff
+            delta_mean = jnp.sum(deltas_att * mask[:, None], axis=0) / m_eff
+        metrics = {
+            "loss": loss,
+            "b": b_new.b,
+            "theta_mse": jnp.mean((theta - delta_mean) ** 2),
+        }
     return new_state, metrics
 
 
@@ -626,17 +643,18 @@ def _scan_chunks(
         # range, where `gidx < limit` alone would leave them weighted.
         w_c = ((gidx < limit) & (local < n_loc)).astype(jnp.float32)
 
-        idx = jax.vmap(lambda m: _client_batch_idx(ctx, kb, m))(sel_c)
-        rows = sel_c - data_offset
-        bx = jax.vmap(lambda r, i: client_x[r][i])(rows, idx)
-        by = jax.vmap(lambda r, i: client_y[r][i])(rows, idx)
+        with jax.named_scope("fl.gather"):
+            idx = jax.vmap(lambda m: _client_batch_idx(ctx, kb, m))(sel_c)
+            rows = sel_c - data_offset
+            bx = jax.vmap(lambda r, i: client_x[r][i])(rows, idx)
+            by = jax.vmap(lambda r, i: client_y[r][i])(rows, idx)
 
-        if stateless:
-            w_start = jnp.broadcast_to(w_global, (C, d))
-            res_c = jnp.zeros((C, d), jnp.float32)
-        else:
-            w_start = carry["w_locals"][sel_c]
-            res_c = carry["residuals"][sel_c]
+            if stateless:
+                w_start = jnp.broadcast_to(w_global, (C, d))
+                res_c = jnp.zeros((C, d), jnp.float32)
+            else:
+                w_start = carry["w_locals"][sel_c]
+                res_c = carry["residuals"][sel_c]
 
         def client(w_local, cb):
             return local_prox_train(
@@ -651,13 +669,15 @@ def _scan_chunks(
                 use_kernel=cfg.use_kernels,
             )
 
-        w_new, loss_before, loss_after = jax.vmap(client)(
-            w_start, {"x": bx, "y": by}
-        )
-        deltas = w_new - w_global[None]
-        deltas_att = apply_attack_stream(
-            params.attack_id, k_att, deltas, gidx < n_byz, gidx
-        )
+        with jax.named_scope("fl.train"):
+            w_new, loss_before, loss_after = jax.vmap(client)(
+                w_start, {"x": bx, "y": by}
+            )
+            deltas = w_new - w_global[None]
+        with jax.named_scope("fl.attack"):
+            deltas_att = apply_attack_stream(
+                params.attack_id, k_att, deltas, gidx < n_byz, gidx
+            )
         wire, res_new = ctx.pipeline.compress_wire(
             k_q,
             deltas_att,
@@ -690,11 +710,14 @@ def _scan_chunks(
         if not stateless:
             # mode="drop": padded wrap rows target index n_clients (out of
             # bounds) so they cannot clobber a real client's row.
-            tgt = jnp.where(local < n_loc, sel_c, cfg.n_clients)
-            new["w_locals"] = carry["w_locals"].at[tgt].set(w_new, mode="drop")
-            new["residuals"] = (
-                carry["residuals"].at[tgt].set(res_new, mode="drop")
-            )
+            with jax.named_scope("fl.writeback"):
+                tgt = jnp.where(local < n_loc, sel_c, cfg.n_clients)
+                new["w_locals"] = carry["w_locals"].at[tgt].set(
+                    w_new, mode="drop"
+                )
+                new["residuals"] = (
+                    carry["residuals"].at[tgt].set(res_new, mode="drop")
+                )
         return new, None
 
     carry, _ = jax.lax.scan(body, carry0, jnp.arange(n_chunks) * C)

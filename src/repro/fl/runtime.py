@@ -587,10 +587,16 @@ class FLSimulation:
         # reallocating (the driver below never re-reads the old state).
         # Callers must snapshot arrays (np.asarray) before run(), not hold
         # live references across it.
-        self._round = jax.jit(
-            functools.partial(_rounds.round_fn(self.ctx), self.ctx, self._params),
-            donate_argnums=(1,),
+        round_impl = functools.partial(
+            _rounds.round_fn(self.ctx), self.ctx, self._params
         )
+
+        # named, so that the program and its ops' name paths read
+        # ``jit(fl_round)`` in a profile rather than ``jit(<unknown>)``
+        def fl_round(key, state, batches):
+            return round_impl(key, state, batches)
+
+        self._round = jax.jit(fl_round, donate_argnums=(1,))
         self.history: list[dict] = []
         # One DP event is recorded per executed round; eps_spent in the
         # history is the cumulative budget under cfg.dp_accountant.

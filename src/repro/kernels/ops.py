@@ -318,12 +318,14 @@ def bit_aggregate(
     engine = _engine_arg(engine, interpret)
     m = packed.shape[0]
     b_full = jnp.broadcast_to(b, (n,)).astype(jnp.float32)
-    if engine == "ref":
-        counts = packed_counts(packed)
-    else:
-        counts = bit_count_2d(packed, interpret=engine == "interpret")
-    counts = counts.reshape(-1)[:n]
-    return (2.0 * counts.astype(jnp.float32) - m) / m * b_full
+    with jax.named_scope("fl.count"):
+        if engine == "ref":
+            counts = packed_counts(packed)
+        else:
+            counts = bit_count_2d(packed, interpret=engine == "interpret")
+        counts = counts.reshape(-1)[:n]
+    with jax.named_scope("fl.finalize"):
+        return (2.0 * counts.astype(jnp.float32) - m) / m * b_full
 
 
 @functools.partial(jax.jit, static_argnames=("engine", "interpret"))

@@ -129,8 +129,13 @@ def make_fl_train_step(cfg: ModelConfig, fl: DistFLConfig, param_specs):
 
     batch leaves: (m_seq, n_pods, local_steps, per_batch, ...) where
     m_seq * n_pods = clients_per_round. Metrics include the per-round
-    uplink ``wire_bytes`` (packed, as shipped) next to the
-    ``wire_bytes_int8`` / ``wire_bytes_f32`` baselines.
+    uplink ``wire_bytes`` (packed, as shipped); the int8 and f32 baselines
+    are static (:func:`repro.fl.pytree_wire.pytree_wire_bytes`).
+
+    Each phase of the round runs under a ``jax.named_scope``
+    (``fl.train``, ``fl.compress``, ``fl.count``, ``fl.finalize``,
+    ``fl.update``), which names its ops in a profiler trace and changes
+    nothing in the compiled program.
     """
 
     # The full shared pipeline: Eq.-5 compressor (client half) and the
@@ -165,28 +170,30 @@ def make_fl_train_step(cfg: ModelConfig, fl: DistFLConfig, param_specs):
                 )
                 return new, loss
 
-            local, losses = jax.lax.scan(lstep, params, client_batch)
-            delta = jax.tree.map(lambda a, c: a - c, local, params)
+            with jax.named_scope("fl.train"):
+                local, losses = jax.lax.scan(lstep, params, client_batch)
+                delta = jax.tree.map(lambda a, c: a - c, local, params)
             if probit:
                 # Flatten each leaf to 1-D before adding the client axis:
                 # the TPU compiler emits code that grows with the leaf for
                 # a direct (L, r, c) -> (1, d) relayout (~45 s and 23 MB
                 # per 27.5M-element leaf on v5e); the barrier keeps XLA
                 # from merging the two reshapes back into that one.
-                d_leaves = [
-                    jax.lax.optimization_barrier(dl.reshape(-1))
-                    for dl in jax.tree.leaves(delta)
-                ]
-                out = [
-                    compressor.compress(
-                        leaf_key(key, i),
-                        dl[None].astype(jnp.float32),
-                        b,
-                        jnp.zeros((), jnp.float32),  # EF off on the mesh path
-                        row_offset=gidx,
-                    )[0].packed
-                    for i, (dl, d) in enumerate(zip(d_leaves, dims))
-                ]
+                with jax.named_scope("fl.compress"):
+                    d_leaves = [
+                        jax.lax.optimization_barrier(dl.reshape(-1))
+                        for dl in jax.tree.leaves(delta)
+                    ]
+                    out = [
+                        compressor.compress(
+                            leaf_key(key, i),
+                            dl[None].astype(jnp.float32),
+                            b,
+                            jnp.zeros((), jnp.float32),  # EF off on the mesh path
+                            row_offset=gidx,
+                        )[0].packed
+                        for i, (dl, d) in enumerate(zip(d_leaves, dims))
+                    ]
             else:
                 out = delta  # full-precision upload (FedAvg baseline)
             return out, (losses[0], losses[-1])
@@ -213,8 +220,10 @@ def make_fl_train_step(cfg: ModelConfig, fl: DistFLConfig, param_specs):
                 acc = jax.tree.map(
                     lambda c, d: c + d.astype(jnp.float32), acc, contrib
                 )
-            votes = votes + jnp.sum(jnp.where(l1 < l0, 1, -1))
-            return (acc, votes), (jnp.mean(l0), jnp.mean(l1))
+            with jax.named_scope("fl.update"):
+                votes = votes + jnp.sum(jnp.where(l1 < l0, 1, -1))
+                losses = (jnp.mean(l0), jnp.mean(l1))
+            return (acc, votes), losses
 
         if probit:
             # per-leaf int32 vote-count carries, one row per pod
@@ -234,16 +243,18 @@ def make_fl_train_step(cfg: ModelConfig, fl: DistFLConfig, param_specs):
         # the single cross-pod reduction: int32 counts (exact up to 2**31
         # clients — NOT the uint8 wire dtype) / f32 delta sums
         if probit:
-            acc = [jnp.sum(a, axis=0, dtype=jnp.int32) for a in acc]
+            with jax.named_scope("fl.count"):
+                acc = [jnp.sum(a, axis=0, dtype=jnp.int32) for a in acc]
 
             # Eq. 13 ML estimate per leaf from the exact vote counts
-            new_leaves = [
-                (
-                    w.astype(jnp.float32)
-                    + server.finalize(cnt, m_total, compressor.b_vector(d, b)).reshape(w.shape)
-                ).astype(w.dtype)
-                for w, cnt, d in zip(p_leaves, acc, dims)
-            ]
+            with jax.named_scope("fl.update"):
+                new_leaves = [
+                    (
+                        w.astype(jnp.float32)
+                        + server.finalize(cnt, m_total, compressor.b_vector(d, b)).reshape(w.shape)
+                    ).astype(w.dtype)
+                    for w, cnt, d in zip(p_leaves, acc, dims)
+                ]
             new_params = jax.tree_util.tree_unflatten(treedef, new_leaves)
             wire_row_bytes = sum(pbytes)
         else:
@@ -255,17 +266,16 @@ def make_fl_train_step(cfg: ModelConfig, fl: DistFLConfig, param_specs):
             )
             wire_row_bytes = 4 * sum(dims)
 
-        b_new = update_b_dist(b, votes, fl)
-        metrics = {
-            "loss_first": jnp.mean(loss0),
-            "loss_last": jnp.mean(loss1),
-            "b": b_new,
-            # f32 round-trips ~7 digits; exact ints come from
-            # fl.pytree_wire.pytree_wire_bytes (static, outside the jit)
-            "wire_bytes": jnp.float32(m_total * wire_row_bytes),
-            "wire_bytes_int8": jnp.float32(m_total * sum(dims)),
-            "wire_bytes_f32": jnp.float32(m_total * 4 * sum(dims)),
-        }
+        with jax.named_scope("fl.update"):
+            b_new = update_b_dist(b, votes, fl)
+            metrics = {
+                "loss_first": jnp.mean(loss0),
+                "loss_last": jnp.mean(loss1),
+                "b": b_new,
+                # f32 round-trips ~7 digits; exact ints come from
+                # fl.pytree_wire.pytree_wire_bytes (static, outside the jit)
+                "wire_bytes": jnp.float32(m_total * wire_row_bytes),
+            }
         return new_params, b_new, metrics
 
     return train_step
