@@ -170,15 +170,76 @@ def codes_to_counts(codes: jax.Array) -> jax.Array:
 # The canonical on-the-wire representation of a round is the (M, d_pad/8)
 # uint8 matrix of packed one-bit codes. The helpers below produce and
 # consume it in d-chunks so the dense (M, d) codes tensor never
-# materializes — peak extra memory is O(M * PACK_CHUNK) regardless of d.
+# materializes. A walk takes ``chunks_per_step`` chunks per loop step, so
+# peak extra memory is O(WALK_BUDGET) regardless of d, and a few clients'
+# walk is not one tiny loop step per chunk.
 # ---------------------------------------------------------------------------
 
-PACK_CHUNK = 8192  # coordinates per chunked-reduction step (multiple of 8)
+PACK_CHUNK = 8192  # coordinates per chunk of the random schedule (multiple of 8)
+WALK_BUDGET = 1 << 22  # coordinates x clients per loop step of a chunk walk
 
 
 def padded_dim(d: int, chunk: int = PACK_CHUNK) -> int:
     """Wire dimension: ``d`` rounded up to a whole number of chunks."""
     return ((d + chunk - 1) // chunk) * chunk
+
+
+def chunks_per_step(rows: int, chunk: int = PACK_CHUNK) -> int:
+    """Chunks a walk over ``rows`` clients handles per loop step.
+
+    As many as ``WALK_BUDGET`` coordinates x clients allow, and at least
+    one: a cohort of ``WALK_BUDGET // chunk`` clients or more walks one
+    chunk per step, a single client 512 chunks per step.
+    """
+    return max(1, WALK_BUDGET // (rows * chunk))
+
+
+def _chunk_walk(body, n_chunks: int, k: int, *arrays):
+    """Run ``body(j, *chunk_j_of_each_array)`` over chunks ``j < n_chunks``.
+
+    Each array is cut along its last axis into ``n_chunks`` equal chunks,
+    and each output of ``body`` is laid end to end along its last axis, in
+    chunk order. A loop step runs ``body`` under ``vmap`` on ``k``
+    consecutive chunks and writes their outputs in place; the last step
+    starts early enough to end at the last chunk, rewriting chunks the
+    step before it wrote. Each chunk's output depends on its index and
+    data alone, so the result does not depend on ``k``. The outputs are
+    written along their last axis, not stacked and reshaped afterwards:
+    for a stacked result the v5e compiler emitted a relayout whose code
+    grows with the array (tens of MB for a 275M-coordinate leaf).
+    """
+    k = min(k, n_chunks)
+
+    def chunks(a, j, n):  # n consecutive chunks of a, from chunk j
+        w = a.shape[-1] // n_chunks
+        return jax.lax.dynamic_slice_in_dim(a, j * w, n * w, axis=a.ndim - 1)
+
+    def joined(y):  # (n, ..., w) -> (..., n * w)
+        return jnp.moveaxis(y, 0, -2).reshape(y.shape[1:-1] + (-1,))
+
+    def block(s):
+        j0 = jnp.minimum(s * k, n_chunks - k)
+        blocks = [chunks(a, j0, k).reshape(a.shape[:-1] + (k, -1)) for a in arrays]
+        ys = jax.vmap(body, in_axes=(0,) + (-2,) * len(arrays))(
+            j0 + jnp.arange(k), *blocks
+        )
+        return j0, jax.tree.map(joined, ys)
+
+    def step(s, out):
+        j0, ys = block(s)
+        return jax.tree.map(
+            lambda o, y: jax.lax.dynamic_update_slice_in_dim(
+                o, y, j0 * (y.shape[-1] // k), axis=y.ndim - 1
+            ),
+            out,
+            ys,
+        )
+
+    out = jax.tree.map(
+        lambda y: jnp.zeros(y.shape[:-1] + (y.shape[-1] // k * n_chunks,), y.dtype),
+        jax.eval_shape(block, 0)[1],
+    )
+    return jax.lax.fori_loop(0, -(-n_chunks // k), step, out)
 
 
 def client_uniforms(
@@ -366,10 +427,7 @@ def packed_binarize_batch(
         row_offset + jnp.arange(m)
     )
 
-    def one_chunk(j):
-        dch = jax.lax.dynamic_slice_in_dim(deltas_p, j * chunk, chunk, axis=1)
-        bch = jax.lax.dynamic_slice_in_dim(b_full, j * chunk, chunk, axis=0)
-
+    def one_chunk(j, dch, bch):
         def per_client(ck, drow):
             kj = jax.random.fold_in(ck, j)
             if rand_bits == 16:
@@ -387,11 +445,11 @@ def packed_binarize_batch(
 
         return jax.vmap(per_client)(client_keys, dch)
 
-    packed_c, res_c = jax.lax.map(one_chunk, jnp.arange(n_chunks))
-    packed = jnp.moveaxis(packed_c, 0, 1).reshape(m, d_pad // 8)
+    packed, res = _chunk_walk(
+        one_chunk, n_chunks, chunks_per_step(m, chunk), deltas_p, b_full
+    )
     if want_residual:
-        res = jnp.moveaxis(res_c, 0, 1).reshape(m, d_pad)[:, :d]
-        return packed, res
+        return packed, res[:, :d]
     return packed, None
 
 
@@ -441,25 +499,17 @@ def packed_quantize_batch(
     n_levels = 1 << bits
     m, d = deltas.shape
     deltas_p, b_full, d_pad = _pad_batch(deltas, b, chunk)
-    gamma_full = None
+    walked = [deltas_p, b_full]
     if gamma is not None:
-        gamma_full = jnp.pad(
-            jnp.broadcast_to(gamma, (d,)).astype(jnp.float32), (0, d_pad - d)
+        walked.append(
+            jnp.pad(jnp.broadcast_to(gamma, (d,)).astype(jnp.float32), (0, d_pad - d))
         )
     n_chunks = d_pad // chunk
     client_keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
         row_offset + jnp.arange(m)
     )
 
-    def one_chunk(j):
-        dch = jax.lax.dynamic_slice_in_dim(deltas_p, j * chunk, chunk, axis=1)
-        bch = jax.lax.dynamic_slice_in_dim(b_full, j * chunk, chunk, axis=0)
-        gch = (
-            None
-            if gamma_full is None
-            else jax.lax.dynamic_slice_in_dim(gamma_full, j * chunk, chunk, 0)
-        )
-
+    def one_chunk(j, dch, bch, gch=None):
         def per_client(ck, drow):
             kj = jax.random.fold_in(ck, j)
             u = jax.random.uniform(kj, (chunk,), dtype=jnp.float32)
@@ -479,12 +529,13 @@ def packed_quantize_batch(
 
         return jax.vmap(per_client)(client_keys, dch)
 
-    packed_c, res_c = jax.lax.map(one_chunk, jnp.arange(n_chunks))
-    # (n_chunks, M, bits, chunk/8) -> (M, bits, n_chunks, chunk/8)
-    packed = jnp.moveaxis(packed_c, 0, 2).reshape(m, bits * d_pad // 8)
+    packed, res = _chunk_walk(
+        one_chunk, n_chunks, chunks_per_step(m, chunk), *walked
+    )
+    # (M, bits, d_pad/8): each plane in the one-bit wire's chunk order
+    packed = packed.reshape(m, bits * d_pad // 8)
     if want_residual:
-        res = jnp.moveaxis(res_c, 0, 1).reshape(m, d_pad)[:, :d]
-        return packed, res
+        return packed, res[:, :d]
     return packed, None
 
 
@@ -529,8 +580,10 @@ def _chunked_bit_counts(
 
     One chunk-layout / pad-handling implementation serves both the integer
     and the weighted count so the two can never diverge; only the
-    per-chunk reduction differs. The integer count uses the popcount
-    reduction (:func:`_popcount_colsums`) unless ``use_popcount=False``
+    per-chunk reduction differs. Counts are per column, so a loop step may
+    take as many bytes as ``chunks_per_step`` allows without changing a
+    count. The integer count uses the popcount reduction
+    (:func:`_popcount_colsums`) unless ``use_popcount=False``
     selects the unpack-and-sum reference (kept for the microbenchmark and
     as the semantics oracle); the weighted count must unpack (a per-client
     f32 multiply cannot ride a popcount).
@@ -541,8 +594,7 @@ def _chunked_bit_counts(
     packed = jnp.pad(packed, ((0, 0), (0, pb_pad - pbytes)))
     shifts = jnp.arange(8, dtype=jnp.uint8)
 
-    def one_chunk(j):
-        pch = jax.lax.dynamic_slice_in_dim(packed, j * cb, cb, axis=1)
+    def one_chunk(j, pch):
         if weights is None and use_popcount:
             return _popcount_colsums(pch)
         bits = (pch[..., None] >> shifts) & jnp.uint8(1)  # (M, cb, 8)
@@ -552,8 +604,9 @@ def _chunked_bit_counts(
             acc = bits.astype(jnp.float32) * weights[:, None, None]
         return jnp.sum(acc, axis=0).reshape(cb * 8)
 
-    counts = jax.lax.map(one_chunk, jnp.arange(pb_pad // cb)).reshape(-1)
-    return counts[: 8 * pbytes]
+    # popcount pads clients to octets; a step's budget counts the pad rows
+    k = chunks_per_step(-(-m // 8) * 8, chunk)
+    return _chunk_walk(one_chunk, pb_pad // cb, k, packed)[: 8 * pbytes]
 
 
 def packed_counts(
@@ -561,8 +614,9 @@ def packed_counts(
 ) -> jax.Array:
     """Vote counts ``N_i`` straight from the packed wire, chunked over d.
 
-    packed: (M, P) uint8 -> counts (8 * P,) int32. Only O(M * chunk) bits
-    are unpacked at a time; the int8 code matrix never materializes.
+    packed: (M, P) uint8 -> counts (8 * P,) int32. Only a loop step's
+    ``WALK_BUDGET`` bits are unpacked at a time; the int8 code matrix never
+    materializes.
     ``use_popcount=False`` forces the unpack-and-sum reference reduction
     (identical integer counts; see ``benchmarks/kernels_micro.py`` for the
     measured difference).
@@ -597,18 +651,17 @@ def packed_residuals(
     :func:`packed_counts`.
     """
     m, d = deltas.shape
-    cb = chunk // 8
     shifts = jnp.arange(8, dtype=jnp.uint8)
     deltas_p, b_full, d_pad = _pad_batch(deltas, b, chunk)
     pbytes = packed.shape[1]
     packed = jnp.pad(packed, ((0, 0), (0, max(d_pad // 8 - pbytes, 0))))
 
-    def one_chunk(j):
-        pch = jax.lax.dynamic_slice_in_dim(packed, j * cb, cb, axis=1)
-        dch = jax.lax.dynamic_slice_in_dim(deltas_p, j * chunk, chunk, axis=1)
-        bch = jax.lax.dynamic_slice_in_dim(b_full, j * chunk, chunk, axis=0)
-        bits = ((pch[..., None] >> shifts) & jnp.uint8(1)).reshape(m, cb * 8)
+    def one_chunk(j, pch, dch, bch):
+        bits = ((pch[..., None] >> shifts) & jnp.uint8(1)).reshape(m, chunk)
         return dch - jnp.where(bits > 0, bch, -bch)
 
-    res = jax.lax.map(one_chunk, jnp.arange(d_pad // chunk))
-    return jnp.moveaxis(res, 0, 1).reshape(m, d_pad)[:, :d]
+    res = _chunk_walk(
+        one_chunk, d_pad // chunk, chunks_per_step(m, chunk),
+        packed[:, : d_pad // 8], deltas_p, b_full,
+    )
+    return res[:, :d]
